@@ -44,6 +44,9 @@ def main() -> None:
                     help="run each suite's QUICK subset (CI smoke)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (calibration_sweep, chaos_sweep,
                             cost_model_bench, device_knobs, dryrun_summary,
                             kernel_autotune_sweep, quant_sweep,

@@ -189,14 +189,14 @@ def device_parallel_for(
 
     from jax.sharding import PartitionSpec as P
 
-    spec = P(axis, *(None,) * (blocked.ndim - 1))
+    # blocks split over ``axis``; trailing dims (whatever rank ``fn``
+    # returns per row) stay whole on each worker
+    spec = P(axis)
 
     def worker(chunk):
         return jax.vmap(jax.vmap(fn))(chunk)
 
-    from repro.core import compat
-
-    out = compat.shard_map(
+    out = jax.shard_map(
         worker, mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False
     )(blocked)
     inv = np.argsort(perm, kind="stable")

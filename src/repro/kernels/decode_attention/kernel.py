@@ -26,7 +26,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import autotune, compat
+from repro.core import autotune
 
 NEG_INF = -1e30
 
@@ -107,7 +107,7 @@ def decode_attention_fwd(
             jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
@@ -234,8 +234,8 @@ def decode_attention_fwd_pipelined(
         grid=(b, hkv),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda b_, h, *_: (b_, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, ns, g, d),
@@ -259,7 +259,7 @@ def decode_attention_fwd_pipelined(
             jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(**params),
+        compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
         name="flash_decode_pipelined",
     )(kv_len.astype(jnp.int32), qt, kt, vt)
@@ -364,7 +364,7 @@ def decode_attention_fwd_quantized(
             jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, ns, g, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,
@@ -467,7 +467,7 @@ def paged_decode_attention_fwd(
             jax.ShapeDtypeStruct((b, hkv, pages, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, pages, g, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -587,8 +587,8 @@ def paged_decode_attention_fwd_pipelined(
         grid=(b, hkv),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda b_, h, *_: (b_, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, pages, g, d),
@@ -612,7 +612,7 @@ def paged_decode_attention_fwd_pipelined(
             jax.ShapeDtypeStruct((b, hkv, pages, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, pages, g, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(**params),
+        compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
         name="paged_flash_decode_pipelined",
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32), qt, kt, vt)
@@ -719,7 +719,7 @@ def paged_decode_attention_fwd_quantized(
             jax.ShapeDtypeStruct((b, hkv, pages, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, pages, g, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -850,10 +850,10 @@ def paged_decode_attention_fwd_quantized_pipelined(
         grid=(b, hkv),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda b_, h, *_: (b_, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, pages, g, d),
@@ -879,7 +879,7 @@ def paged_decode_attention_fwd_quantized_pipelined(
             jax.ShapeDtypeStruct((b, hkv, pages, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, pages, g, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(**params),
+        compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
         name="paged_flash_decode_quantized_pipelined",
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),

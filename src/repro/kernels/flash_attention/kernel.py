@@ -19,7 +19,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import compat
 
 NEG_INF = -1e30
 
@@ -120,7 +119,7 @@ def flash_attention_fwd(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -278,8 +277,8 @@ def flash_attention_fwd_pipelined(
         grid=(b, hq, nq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, i: (b_, h, i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, i: (b_, h, i, 0)),
@@ -297,7 +296,7 @@ def flash_attention_fwd_pipelined(
             pltpu.VMEM((nb, bk, d), vt.dtype),
             pltpu.SemaphoreType.DMA((2, nb)),
         ],
-        compiler_params=compat.tpu_compiler_params(**params),
+        compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
         name="flash_attention_fwd_pipelined",
     )(qt, kt, vt)
@@ -425,7 +424,7 @@ def flash_attention_fwd_quantized(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -561,7 +560,7 @@ def flash_attention_bwd(
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -592,7 +591,7 @@ def flash_attention_bwd(
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core import autotune, compat
+from repro.core import autotune
 
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nd: int):
@@ -66,7 +66,7 @@ def gmm(
         out_specs=pl.BlockSpec((1, bc, bf), lambda e_, i, j, kd: (e_, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -129,7 +129,7 @@ def gmm_quantized(
         out_specs=pl.BlockSpec((1, bc, bf), lambda e_, i, j, kd: (e_, i, j)),
         out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,4 +1,7 @@
 import os
+# 512 virtual devices of the CPU backend, asked for by name: on a machine
+# with a TPU the default backend would otherwise be the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) on the production

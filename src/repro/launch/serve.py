@@ -5,11 +5,18 @@ or drive the continuous-batching engine over a mixed-length workload.
         --batch 4 --prompt-len 16 --tokens 32
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --reduced \
         --requests 16 --tokens 24 --schedule hierarchical --slots 4
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b \
+        --requests 8 --prompt-len 1024 --tokens 32 --cache paged
+
+Parameters and the KV cache are bfloat16.  Weights are random (seed 0)
+unless ``--ckpt-dir`` names a checkpoint.  With ``--requests``, the
+process exits non-zero when any request ends failed or shed.
 """
 
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import jax
@@ -18,11 +25,40 @@ import numpy as np
 from repro.checkpoint import checkpoint as ckpt
 from repro.configs import get_config
 from repro.configs.inputs import make_dummy_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serve.engine import Engine, ServeConfig
 
+DTYPE = "bfloat16"
 
-def main():
+
+def init_model(arch: str, *, reduced: bool = False, seed: int = 0):
+    """(model, params) in bfloat16.  ``Model.init`` runs under ``jax.jit``,
+    so every weight is made on the device in its storage dtype."""
+    cfg = get_config(arch).with_dtype(DTYPE)
+    if reduced:
+        cfg = cfg.reduced()
+    model = Model(cfg)
+    return model, jax.jit(model.init)(jax.random.PRNGKey(seed))
+
+
+def make_prompts(vocab_size: int, n: int, prompt_len: int,
+                 seed: int = 0) -> list:
+    """``n`` prompts of ``prompt_len // 8`` to ``prompt_len`` random tokens."""
+    rng = np.random.RandomState(seed)
+    return [rng.randint(1, vocab_size, int(l)).astype(np.int32)
+            for l in rng.randint(max(2, prompt_len // 8), prompt_len + 1, n)]
+
+
+def serve_max_len(prompt_len: int, tokens: int, page_size: int = 16) -> int:
+    """Cache length for prompts up to ``prompt_len`` plus ``tokens`` new
+    ones: the enclosing power of two, in whole pages."""
+    need = prompt_len + tokens + 1
+    max_len = 1 << (need - 1).bit_length()
+    return -(-max_len // page_size) * page_size
+
+
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -53,45 +89,38 @@ def main():
     ap.add_argument("--kv-dtype", default=None,
                     help="quantized KV cache storage, e.g. int8 or "
                          "float8_e4m3fn (default: the compute dtype)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    model, params = init_model(args.arch, reduced=args.reduced)
+    cfg = model.cfg
     if args.ckpt_dir:
         tree, step = ckpt.restore(args.ckpt_dir, like={"params": params})
         params = tree["params"]
         print(f"loaded checkpoint step {step}")
 
     if args.requests > 0:
-        max_len = args.prompt_len + args.tokens + 1
-        if args.cache == "paged":       # pool leaves come in whole pages
-            round_to = args.page_size or 16
-            max_len = -(-max_len // round_to) * round_to
         eng = Engine(model, params, ServeConfig(
-            max_len=max_len,
+            max_len=serve_max_len(args.prompt_len, args.tokens,
+                                  args.page_size or 16),
             temperature=args.temperature, slots=args.slots,
+            cache_dtype=DTYPE,
             refill_schedule=args.schedule, mode=args.mode,
             cache=args.cache, page_size=args.page_size or None,
             num_pages=args.num_pages, kv_dtype=args.kv_dtype))
-        rng = np.random.RandomState(0)
-        prompts = [rng.randint(1, cfg.vocab_size, int(l)).astype(np.int32)
-                   for l in rng.randint(max(2, args.prompt_len // 4),
-                                        args.prompt_len + 1,
-                                        args.requests)]
+        prompts = make_prompts(cfg.vocab_size, args.requests,
+                               args.prompt_len)
         outs = eng.serve(prompts, args.tokens)
         rep = eng.last_report
         print(f"served {len(outs)} requests x <= {args.tokens} tokens "
               f"[{args.mode}/{args.schedule}] in {rep.wall_s:.2f}s")
         for k, v in rep.as_row().items():
             print(f"  {k:24s} {v}")
-        return
+        return 1 if rep.failed_requests or rep.shed_requests else 0
 
     eng = Engine(model, params, ServeConfig(
         max_len=args.prompt_len + args.tokens + 1,
-        temperature=args.temperature))
+        temperature=args.temperature, cache_dtype=DTYPE))
     batch = make_dummy_batch(cfg, args.batch, args.prompt_len)
     t0 = time.time()
     out = eng.generate(batch, args.tokens)
@@ -99,7 +128,8 @@ def main():
     print(f"generated {out.shape} in {dt:.2f}s "
           f"({out.size / dt:.1f} tok/s incl. compile)")
     print("sample:", out[0][:16])
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -4,8 +4,9 @@
         --steps 200 --batch 8 --seq 128
 
 --reduced runs the CPU-scale config (the full configs are for the dry-run /
-real pods).  On a real TPU slice this same entry point shards over the
-production mesh (--mesh production) via the sharding policy.
+real pods).  The launcher trains on the default device with no mesh; the
+sharded step (``repro.distributed`` policy over ``launch.mesh``) is driven
+by the dry-run and the distributed tests, not by this entry point.
 """
 
 from __future__ import annotations
@@ -16,6 +17,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.train.optimizer import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
@@ -40,6 +42,7 @@ def main():
                     help="run the fast online FAA-cost calibration first "
                          "(persists results/calibration.json)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.calibrate:
         from repro.core import runtime

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import autotune, compat
+from repro.core import autotune
 from repro.kernels import quant
 from repro.models import layers
 
@@ -181,7 +181,7 @@ def distributed_decode_attention(q, k, v, kv_len, *, mesh, axis="model",
         1, int(np.prod([mesh.shape[a] for a in ba]))) == 0 else ()
     bspec = ba if ba else None
     kvl = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32), (q.shape[0],))
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bspec, None, None), P(bspec, axis, None, None),
                   P(bspec, axis, None, None), P(bspec)),

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import compat
 from repro.distributed.sharding import active_policy
 from repro.models import layers
 from repro.models.moe import MoEConfig, moe_apply, prefix_sum_slots
@@ -116,7 +115,7 @@ def moe_apply_sharded(
         return out, aux, kept
 
     tok_spec = P(token_axes, None)
-    out, aux, kept = compat.shard_map(
+    out, aux, kept = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(None, None),
                   P("model", "data", None), P("model", "data", None),

@@ -63,12 +63,13 @@ def test_elastic_remesh(tmp_path):
     spec) — on CPU we emulate with different PartitionSpecs on a 1-device
     mesh; the API path (shardings= tree) is identical on a pod."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh_a = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh_a = make_mesh((1,), ("data",))
     t = {"w": jnp.arange(16.0).reshape(4, 4)}
     sharded = jax.device_put(t["w"], NamedSharding(mesh_a, P("data", None)))
     ckpt.save({"w": sharded}, tmp_path, 1)
 
-    mesh_b = jax.make_mesh((1,), ("model",))
+    mesh_b = make_mesh((1,), ("model",))
     like = {"w": jnp.zeros((4, 4))}
     shardings = {"w": NamedSharding(mesh_b, P(None, "model"))}
     restored, _ = ckpt.restore(tmp_path, like=like, shardings=shardings)

@@ -3,6 +3,7 @@ runs in a subprocess so the device-count flag doesn't leak into this
 process.  Covers: sharded train step under the policy (TP and pure-FSDP
 layouts), shard_map MoE inside a full model, elastic checkpoint remesh."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -23,7 +24,8 @@ SCRIPT = textwrap.dedent("""
     from repro.train import optimizer as opt_mod
     from repro.train.train_step import make_train_step
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     # ---- sharded train step: MoE arch with shard_map dispatch ----
     cfg = get_config("deepseek-v2-lite-16b").reduced()
@@ -68,7 +70,7 @@ SCRIPT = textwrap.dedent("""
     import tempfile
     d = tempfile.mkdtemp()
     ckpt.save({"p": params2}, d, 1)
-    mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh2 = make_mesh((4, 2), ("data", "model"))
     p_sh3 = psh.param_shardings(jax.eval_shape(lambda: params2), mesh2,
                                 layout="tp")
     restored, _ = ckpt.restore(d, like={"p": params2},
@@ -112,11 +114,13 @@ SCRIPT = textwrap.dedent("""
 
 
 def test_distributed_integration():
+    # the parent's environment (JAX_PLATFORMS above all) reaches the child
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (str(REPO / "src") + os.pathsep
+                         + env.get("PYTHONPATH", ""))
     r = subprocess.run(
         [sys.executable, "-c", SCRIPT],
-        capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": str(REPO / "src"), "HOME": "/root",
-             "PATH": "/usr/bin:/bin:/usr/local/bin"},
+        capture_output=True, text=True, timeout=900, env=env,
         cwd=str(REPO))
     out = r.stdout
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-3000:])

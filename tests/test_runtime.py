@@ -278,7 +278,8 @@ def test_device_parallel_for_padding_branches():
     code = "\n".join([
         "import numpy as np, jax, jax.numpy as jnp",
         "from repro.core import parallel_for as pf",
-        "mesh = jax.make_mesh((4,), ('data',))",
+        "from repro.launch.mesh import make_mesh",
+        "mesh = make_mesh((4,), ('data',))",
         "items = jnp.arange(37.0)",
         "# b=5 -> blocks=8 (divisible by 4 workers): pad=3>0, pad_blocks=0",
         "out = pf.device_parallel_for(lambda x: x * 2 + 1, items,",

@@ -58,7 +58,8 @@ def test_param_rules_cover_all_archs():
     """Every leaf of every arch must resolve to a sharding under both
     rule sets without error (uses abstract init — no allocation)."""
     from repro.models import Model
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     for arch in ("granite-3-2b", "deepseek-v2-lite-16b", "mamba2-780m",
                  "zamba2-2.7b", "seamless-m4t-large-v2"):
         cfg = get_config(arch).reduced()

@@ -4,6 +4,7 @@ autotuner wiring, roofline parser."""
 
 import shutil
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -153,12 +154,17 @@ def test_prefetch_iterator_retries_skipped_stragglers():
             out = super().batch(step)
             if step == 1 and 1 not in getattr(self, "_slowed", set()):
                 self._slowed = {1}
-                time.sleep(0.05)
+                time.sleep(1.0)
             return out
 
+    # the slow step takes five times the timeout; the timeout itself sits
+    # far above an ordinary two-example batch even on a loaded host
     cfg = DataConfig(vocab_size=100, seq_len=8, global_batch=2,
                      host_threads=2, prefetch=4,
-                     straggler_timeout_s=0.01)
+                     straggler_timeout_s=0.2)
+    # warm the source (tuning context, worker pool) before the straggler
+    # clock runs: a cold first batch must not read as a straggler
+    SyntheticLM(cfg).batch(0)
     it = PrefetchIterator(OneSlowStep(cfg), start_step=0, num_steps=4)
     got = [s for s, _ in it]
     it.close()
@@ -311,3 +317,40 @@ def test_roofline_parser_counts_scanned_dots():
     expected = 3 * k * 2 * 8 * m * m
     assert stats.flops == pytest.approx(expected, rel=0.34), (
         stats.flops, expected)
+
+
+def test_compile_cache_dir_follows_env_else_checkout(monkeypatch):
+    """The entry points' compile cache: JAX_COMPILATION_CACHE_DIR when set
+    (and then nothing is configured), else a fixed <checkout>/.jax_cache.
+    Nothing is compiled, so the cache is never opened."""
+    from repro.launch import compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/srv/shared-cache")
+    assert compile_cache.enable_compile_cache() == "/srv/shared-cache"
+    assert jax.config.jax_compilation_cache_dir == prev
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_serve_launcher_exits_nonzero_on_failed_requests(monkeypatch,
+                                                         capsys):
+    """launch/serve.py reports a failed request in its exit code, not
+    only in the printed report."""
+    from repro.core import faults
+    from repro.launch import serve as launch
+
+    monkeypatch.setattr(launch, "enable_compile_cache", lambda: None)
+    argv = ["--arch", "qwen2.5-3b", "--reduced", "--requests", "3",
+            "--prompt-len", "16", "--tokens", "4"]
+    assert launch.main(argv) == 0
+    plan = faults.FaultPlan(seed=1, specs=[faults.PoisonRequest(rids=(1,))])
+    with faults.fault_scope(plan):
+        assert launch.main(argv) == 1
+    assert "failed                   1" in capsys.readouterr().out

@@ -38,8 +38,8 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="sim | cost | taskflow | sched | serve | paged "
-                         "| device | roofline | calib | kautotune | quant "
-                         "| chaos | spec")
+                         "| roofline | calib | kautotune | quant | chaos "
+                         "| spec")
     ap.add_argument("--quick", action="store_true",
                     help="run each suite's QUICK subset (CI smoke)")
     args = ap.parse_args()
@@ -48,7 +48,7 @@ def main() -> None:
     enable_compile_cache()
 
     from benchmarks import (calibration_sweep, chaos_sweep,
-                            cost_model_bench, device_knobs, dryrun_summary,
+                            cost_model_bench, dryrun_summary,
                             kernel_autotune_sweep, quant_sweep,
                             scheduler_sweep, serve_admission_sweep,
                             serve_paged_sweep, sim_tables,
@@ -61,7 +61,6 @@ def main() -> None:
         "sched": scheduler_sweep,
         "serve": serve_admission_sweep,
         "paged": serve_paged_sweep,
-        "device": device_knobs,
         "roofline": dryrun_summary,
         "calib": calibration_sweep,
         "kautotune": kernel_autotune_sweep,

@@ -55,6 +55,11 @@ from repro.serve.telemetry import RequestTelemetry, ServeReport
 # that a 1-D token prompt cannot carry)
 _SERVABLE = ("dense", "moe", "ssm", "hybrid")
 
+# host spans in the profiler's own trace, on the device trace's clock
+# (about a microsecond each while no profiler runs); docs/serving.md
+# lists the span tree
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class SpecConfig:
@@ -162,15 +167,24 @@ class Engine:
         # model's caches carry scale leaves — see models/attention.py
         self.kv_dtype = jnp.dtype(cfg.kv_dtype or cfg.cache_dtype)
         kvd = self.kv_dtype
-        self._prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, cfg.max_len, kvd))
-        self._prefill_padded = jax.jit(
-            lambda p, toks, lens: model.prefill_padded(
-                p, {"tokens": toks, "lengths": lens}, cfg.max_len, kvd))
-        self._decode = jax.jit(model.decode_step)
+
+        # every program is a named def, so a profiler trace names it
+        # ``jit_<def>``; only the target's own step is ``jit_decode_step``
+        def prefill(p, b):
+            return model.prefill(p, b, cfg.max_len, kvd)
+
+        def prefill_padded(p, toks, lens):
+            return model.prefill_padded(
+                p, {"tokens": toks, "lengths": lens}, cfg.max_len, kvd)
+
         # greedy decode transfers [B] token ids, never [B, vocab] logits
-        self._argmax = jax.jit(
-            lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        def argmax_tokens(logits):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        self._prefill = jax.jit(prefill)
+        self._prefill_padded = jax.jit(prefill_padded)
+        self._decode = jax.jit(model.decode_step)
+        self._argmax = jax.jit(argmax_tokens)
         # temperature > 0: one batched categorical per tick over the
         # per-(request, step) key streams — same [B]-ids-only transfer
         # contract as _argmax, and sampling is a pure function of
@@ -178,7 +192,7 @@ class Engine:
         # interleaving, scheduler policy, or batch composition.
         temp = cfg.temperature
 
-        def _sample_fn(logits, seed, rids, steps):
+        def sample_tokens(logits, seed, rids, steps):
             base = jax.random.PRNGKey(seed)
 
             def one(row_logits, rid, step):
@@ -187,16 +201,22 @@ class Engine:
 
             return jax.vmap(one)(logits, rids, steps).astype(jnp.int32)
 
-        self._sample_tokens = jax.jit(_sample_fn) if temp > 0 else None
+        self._sample_tokens = jax.jit(sample_tokens) if temp > 0 else None
         self._splice = None     # built lazily (needs the cache axis probe)
         # ---- speculative decoding (cfg.spec) ----
         if cfg.spec is not None:
             draft = cfg.spec.draft
+
+            def drafter_step(p, tokens, cache):
+                return draft.decode_step(p, tokens, cache)
+
+            def drafter_prefill_padded(p, toks, lens):
+                return draft.prefill_padded(
+                    p, {"tokens": toks, "lengths": lens}, cfg.max_len, kvd)
+
             self._verify = jax.jit(model.verify_step)
-            self._draft_decode = jax.jit(draft.decode_step)
-            self._draft_prefill_padded = jax.jit(
-                lambda p, toks, lens: draft.prefill_padded(
-                    p, {"tokens": toks, "lengths": lens}, cfg.max_len, kvd))
+            self._draft_decode = jax.jit(drafter_step)
+            self._draft_prefill_padded = jax.jit(drafter_prefill_padded)
             # rollback: rewrite per-row cache lengths from the host-
             # tracked accepted lengths (pure truncation — rejected
             # positions stay masked garbage until overwritten)
@@ -389,10 +409,13 @@ class Engine:
             raise ValueError(
                 f"cache={self.cfg.cache!r} needs mode='continuous' "
                 f"(the rounds barrier has no slot lifecycle to page)")
-        if self.cfg.mode == "continuous":
-            return self._serve_continuous(requests, max_new_tokens, seed)
-        if self.cfg.mode == "rounds":
-            return self._serve_rounds(requests, max_new_tokens, seed)
+        with _span("serve.call", requests=len(requests),
+                   slots=self.cfg.slots):
+            if self.cfg.mode == "continuous":
+                return self._serve_continuous(requests, max_new_tokens,
+                                              seed)
+            if self.cfg.mode == "rounds":
+                return self._serve_rounds(requests, max_new_tokens, seed)
         raise ValueError(f"unknown serve mode {self.cfg.mode!r}")
 
     # ------------------------------------------------- continuous batching
@@ -421,17 +444,23 @@ class Engine:
 
     def _ensure_splice(self):
         if self._splice is None:
-            axes = self.model.cache_batch_axes(dtype=self.kv_dtype)
-            self._splice = jax.jit(
-                lambda c, pc, s: self.model.splice_cache(c, pc, s,
-                                                         axes=axes))
+            model = self.model
+            axes = model.cache_batch_axes(dtype=self.kv_dtype)
+
+            def splice_cache(c, pc, s):
+                return model.splice_cache(c, pc, s, axes=axes)
+
+            self._splice = jax.jit(splice_cache)
 
     def _ensure_draft_splice(self):
         if self._draft_splice is None:
             draft = self.cfg.spec.draft
             axes = draft.cache_batch_axes(dtype=self.kv_dtype)
-            self._draft_splice = jax.jit(
-                lambda c, pc, s: draft.splice_cache(c, pc, s, axes=axes))
+
+            def drafter_splice(c, pc, s):
+                return draft.splice_cache(c, pc, s, axes=axes)
+
+            self._draft_splice = jax.jit(drafter_splice)
 
     def _spec_k(self) -> int:
         """Resolved draft span: explicit SpecConfig.k, or the calibrated
@@ -451,12 +480,6 @@ class Engine:
         # fault injection resolves once per serve() call: a single module-
         # global read when no plan is installed (zero-overhead contract)
         inj = _faults.active()
-        block = cfg.admission_block
-        if block is None:
-            block = rt.tuning().admission_block(len(requests), cfg.slots)
-        queue = RequestQueue(requests, cfg.slots, cfg.refill_schedule,
-                             block_size=block)
-        self.refill_stats = [queue.plan.stats]
         tok = np.zeros(cfg.slots, np.int32)
         slot_req: List[Optional[Request]] = [None] * cfg.slots
         slot_cap = np.zeros(cfg.slots, np.int64)
@@ -505,7 +528,15 @@ class Engine:
         if self._backend is None or self._backend.name != cfg.cache:
             self._backend = make_cache_backend(self)
         backend = self._backend
-        backend.begin_call()
+        with _span("serve.plan"):
+            block = cfg.admission_block
+            if block is None:
+                block = rt.tuning().admission_block(len(requests),
+                                                    cfg.slots)
+            queue = RequestQueue(requests, cfg.slots, cfg.refill_schedule,
+                                 block_size=block)
+            backend.begin_call()
+        self.refill_stats = [queue.plan.stats]
         backend.validate(requests, cap_of)
         for req in requests:
             # configuration errors (over-bucket / over-max_len prompts)
@@ -571,299 +602,341 @@ class Engine:
             outputs[req.rid] = None
             retry_or_fail(req, reason)
 
+        tick_end_s: List[float] = []
         while True:
-            # refill every free slot in flight — no round barrier, so a
-            # long sequence elsewhere never blocks this admission
-            progress = False
-            deferred_pass = 0   # admissions bounced on page pressure
-            delayed_pass = 0    # requests held out by retry backoff
-            for s in range(cfg.slots):
-                if slot_req[s] is not None:
-                    continue
-                nxt = queue.next_for(s)
-                if nxt is None:
-                    continue
-                req, stolen = nxt
-                if cap_of(req) < 1:     # zero token budget: nothing to do
-                    outputs[req.rid] = []
-                    telem[req.rid].admit_tick = tick
-                    telem[req.rid].finish_tick = tick
-                    telem[req.rid].finish_s = time.monotonic() - t0
-                    set_terminal(req.rid, "ok")
-                    progress = True
-                    continue
-                if not_before.get(req.rid, 0) > tick:
-                    # retry backoff: not yet eligible — rotate to the back
-                    # of the shallowest backlog (no deferral penalty) so
-                    # it cannot head-of-line block the slot it landed on
-                    queue.requeue(req.rid)
-                    delayed_pass += 1
-                    continue
-                if starving is not None and req.rid != starving:
-                    # aging barrier: a request past the deferral bound is
-                    # waiting on pages, and every small admission here
-                    # would snatch them first — steady churn then defers
-                    # the large request forever.  Hold this slot empty
-                    # (re-queue, no deferral penalty) until the starving
-                    # request lands; running slots drain and free pages.
-                    queue.push_back(s, req)
-                    continue
-                try:
-                    if inj is not None:
-                        inj.check_admission(req.rid)
-                    res = backend.admit(s, req, cap_of(req))
-                except Exception as e:
-                    if not cfg.isolate_failures:
-                        raise
-                    # per-request failure isolation: this admission died
-                    # (a poisoned request, or an organic prefill error
-                    # scoped to it) — the batch survives.  The backend
-                    # reclaims any pages it claimed before re-raising, so
-                    # nothing leaks; the request retries or goes FAILED.
-                    if retry_or_fail(
-                            req, f"admission: {type(e).__name__}: {e}"):
-                        delayed_pass += 1
-                    else:
-                        progress = True
-                    continue
-                if res is None:
-                    # partial admission: the request's page demand exceeds
-                    # the free pool right now — back on this slot's backlog
-                    # (still next in its claim order), retry once decode
-                    # ticks free pages
-                    queue.push_back(s, req)
-                    tm = telem[req.rid]
-                    tm.deferred_ticks += 1
-                    deferred_pass += 1
-                    if (starving is None
-                            and cfg.max_deferred_ticks is not None
-                            and tm.deferred_ticks > cfg.max_deferred_ticks):
-                        starving = req.rid
-                    continue
-                progress = True
-                if req.rid == starving:
-                    starving = None
-                first = self._sample_row(res.logits_row, seed, req.rid, 0)
-                slot_req[s] = req
-                slot_cap[s] = cap_of(req)
-                slot_len[s] = req.prompt_len
-                tok[s] = first
-                outputs[req.rid] = [first]
-                if spec_k:
-                    # the drafter consumes the same prompt into its own
-                    # contiguous cache row (its proposals must continue
-                    # exactly the target's stream)
-                    w = self._bucket_width(req.prompt_len)
-                    dtoks = np.zeros((1, w), np.int32)
-                    dtoks[0, : req.prompt_len] = req.prompt
-                    _, dcache = self._draft_prefill_padded(
-                        spec.draft_params, jnp.asarray(dtoks),
-                        jnp.asarray([req.prompt_len], jnp.int32))
-                    draft_cache = self._draft_splice(
-                        draft_cache, dcache, jnp.asarray(s, jnp.int32))
-                tm = telem[req.rid]
-                tm.admit_tick = tick
-                tm.ttft_s = time.monotonic() - t0
-                tm.stolen = stolen
-                tm.prefill_tokens = res.prefill_tokens
-                tm.prefix_hit_tokens = res.prefix_hit_tokens
-                if first == cfg.eos_id or slot_cap[s] <= 1:
-                    finish(s)
-
-            live = [s for s in range(cfg.slots) if slot_req[s] is not None]
-            if not live and queue.pending == 0:
+            # nothing left: leave before a tick span opens, so that each
+            # serve.tick span is one tick (the check below stays for
+            # passes that empty the queue without decoding)
+            if queue.pending == 0 and all(r is None for r in slot_req):
                 break
-            if not live:
-                if progress:
-                    continue    # every admitted request finished on its
-                                # first token; loop back for the rest
-                if delayed_pass:
-                    # everything actionable is waiting out a retry backoff
-                    # and nothing is running: only the clock can move, so
-                    # charge an idle tick and retry admission
-                    tick += 1
-                    continue
-                # true admission deadlock: nothing running, nothing
-                # admitted, and no decode tick can free pages — retrying
-                # is a spin.  cfg.on_pressure picks the blast radius.
-                if cfg.on_pressure == "shed":
-                    # load shedding: drop the youngest request already
-                    # bounced on pressure (max rid = latest submission —
-                    # the oldest deferred request keeps its aging credit),
-                    # then let the survivors admit into the freed demand
-                    pend = queue.pending_rids()
-                    deferred = [r for r in pend
-                                if telem[r].deferred_ticks > 0]
-                    victim = max(deferred) if deferred else max(pend)
-                    queue.drop(victim)
-                    set_terminal(victim, "shed",
-                                 "load shed: admission deadlock under "
-                                 "page pressure")
-                    continue
-                if cfg.on_pressure == "defer":
-                    # graceful completion: requests that can never admit
-                    # go terminal FAILED and the batch ends around them
-                    for r in list(queue.pending_rids()):
-                        queue.drop(r)
-                        set_terminal(r, "failed",
-                                     "page pressure: admission can never "
-                                     "proceed")
-                    continue
-                # "raise" — the pre-robustness behavior, still the default
-                raise RuntimeError(
-                    f"refill deadlock: {queue.pending} request(s) "
-                    f"pending, no slot live, and no admission can "
-                    f"proceed")
-
-            if inj is not None:
-                # injected decode-loop stall (a straggler engine tick):
-                # charged to the chaos clock and surfaced in the report's
-                # injected_stall_s — the exposed-wait term
-                engine_stall_s += inj.engine_stall(tick)
-            # one unit of per-token decode bookkeeping per (live slot,
-            # tick) — the serving analogue of the per-item FAA the paper
-            # amortizes; speculation emits >1 token per unit
-            decode_slot_ticks += len(live)
-            if spec_k:
-                tick += 1
-                # ---- draft: k sequential batched drafter steps.  Column
-                # 0 is each slot's last emitted (still unconsumed) token;
-                # columns 1..k are the drafter's greedy continuations.
-                draft_block = np.zeros((cfg.slots, spec_k + 1), np.int32)
-                draft_block[:, 0] = tok
-                dtok = jnp.asarray(tok)[:, None]
-                for j in range(1, spec_k + 1):
-                    dlogits, draft_cache = self._draft_decode(
-                        spec.draft_params, dtok, draft_cache)
-                    dtok = self._argmax(dlogits)[:, None]
-                    draft_block[:, j] = np.asarray(dtok)[:, 0]
-                # ---- verify all k+1 positions in one batched forward;
-                # greedy[s, j] is exactly the token a non-speculative
-                # decode tick would emit after consuming draft_block[s,
-                # :j+1] (per-position attention in attn_apply)
-                vlogits, backend.cache = self._verify(
-                    self.params, jnp.asarray(draft_block), backend.cache)
-                greedy = np.asarray(self._argmax(vlogits))
-                # ---- host acceptance: longest matching prefix + one
-                # corrected token, capped by remaining budget, cut at eos
-                decisions = {}
-                full_accept = False
-                for s in live:
-                    rid = slot_req[s].rid
-                    degraded = False
-                    if inj is not None:
-                        try:
-                            inj.check_draft(rid, len(outputs[rid]))
-                        except Exception as e:
-                            if not cfg.isolate_failures:
-                                raise
-                            # poisoned draft: degrade this slot's tick to
-                            # non-speculative decode (accept nothing, emit
-                            # only the corrected token) — the request
-                            # survives, it just loses the amortization
-                            degraded = True
-                    m = 0
-                    if not degraded:
-                        while (m < spec_k and int(draft_block[s, m + 1])
-                               == int(greedy[s, m])):
-                            m += 1
-                    if m == spec_k:
-                        full_accept = True
-                    rem = int(slot_cap[s]) - len(outputs[rid])
-                    emit = [int(t) for t in greedy[s, : min(m + 1, rem)]]
-                    for ei, t in enumerate(emit):
-                        if t == cfg.eos_id:
-                            emit = emit[: ei + 1]
-                            break
-                    decisions[s] = (emit, degraded)
-                if full_accept:
-                    # resync: a fully accepted row's drafter never
-                    # consumed its own k-th proposal; one extra batched
-                    # step feeds it (the length rollback right below
-                    # masks this step for every other row)
-                    _, draft_cache = self._draft_decode(
-                        spec.draft_params,
-                        jnp.asarray(draft_block[:, -1:]), draft_cache)
-                for s, (emit, _) in decisions.items():
-                    slot_len[s] += len(emit)
-                # ---- rollback: both caches truncate to the accepted
-                # lengths; rejected positions become masked garbage
-                # (exactly zero attention weight) until overwritten
-                lens = jnp.asarray(slot_len, jnp.int32)
-                backend.cache = self._set_lens(backend.cache, lens)
-                draft_cache = self._set_lens(draft_cache, lens)
-                for s in live:
-                    rid = slot_req[s].rid
-                    emit, degraded = decisions[s]
-                    tm = telem[rid]
-                    tm.drafted_tokens += spec_k
-                    tm.accepted_tokens += len(emit) - 1
-                    drafted_total += spec_k
-                    accepted_total += len(emit) - 1
-                    if degraded:
-                        degraded_ticks += 1
-                    if inj is not None:
-                        cancelled = False
-                        base = len(outputs[rid])
-                        for off in range(len(emit)):
-                            try:
-                                inj.check_decode(rid, base + off)
-                            except Exception as e:
-                                if not cfg.isolate_failures:
-                                    raise
-                                cancel(s,
-                                       f"decode: {type(e).__name__}: {e}")
-                                cancelled = True
-                                break
-                        if cancelled:
-                            continue
-                    outputs[rid].extend(emit)
-                    tok[s] = emit[-1]
-                    if (emit[-1] == cfg.eos_id
-                            or len(outputs[rid]) >= slot_cap[s]):
-                        finish(s)
-            else:
-                logits, backend.cache = self._decode(
-                    self.params, jnp.asarray(tok)[:, None], backend.cache)
-                tick += 1
-                if cfg.temperature <= 0:
-                    next_toks = np.asarray(self._argmax(logits))
-                else:
-                    # batched per-(request, step) sampling: one transfer
-                    # per tick ([B] ids), never a per-slot host sync
-                    rids_b = np.zeros(cfg.slots, np.int32)
-                    steps_b = np.zeros(cfg.slots, np.int32)
-                    for s in live:
-                        rids_b[s] = slot_req[s].rid
-                        steps_b[s] = len(outputs[slot_req[s].rid])
-                    next_toks = np.asarray(self._sample_tokens(
-                        logits, seed, jnp.asarray(rids_b),
-                        jnp.asarray(steps_b)))
-                for s in live:
-                    rid = slot_req[s].rid
-                    if inj is not None:
-                        try:
-                            inj.check_decode(rid, len(outputs[rid]))
-                        except Exception as e:
-                            if not cfg.isolate_failures:
-                                raise
-                            cancel(s, f"decode: {type(e).__name__}: {e}")
-                            continue
-                    nxt_tok = int(next_toks[s])
-                    tok[s] = nxt_tok
-                    outputs[rid].append(nxt_tok)
-                    if (nxt_tok == cfg.eos_id
-                            or len(outputs[rid]) >= slot_cap[s]):
-                        finish(s)
-            if cfg.deadline_ticks is not None:
+            with _span("serve.tick", tick=tick):
+                # refill every free slot in flight — no round barrier, so
+                # a long sequence elsewhere never blocks this admission
+                progress = False
+                deferred_pass = 0   # admissions bounced on page pressure
+                delayed_pass = 0    # requests held out by retry backoff
                 for s in range(cfg.slots):
-                    req = slot_req[s]
-                    if req is None:
+                    if slot_req[s] is not None:
                         continue
-                    if (tick - telem[req.rid].admit_tick
-                            >= cfg.deadline_ticks):
-                        cancel(s, f"deadline: exceeded {cfg.deadline_ticks}"
-                                  f" decode tick(s) since admission")
+                    nxt = queue.next_for(s)
+                    if nxt is None:
+                        continue
+                    req, stolen = nxt
+                    if cap_of(req) < 1:  # zero token budget: nothing to do
+                        outputs[req.rid] = []
+                        telem[req.rid].admit_tick = tick
+                        telem[req.rid].finish_tick = tick
+                        telem[req.rid].finish_s = time.monotonic() - t0
+                        set_terminal(req.rid, "ok")
+                        progress = True
+                        continue
+                    if not_before.get(req.rid, 0) > tick:
+                        # retry backoff: not yet eligible — rotate to the
+                        # back of the shallowest backlog (no deferral
+                        # penalty) so it cannot head-of-line block the
+                        # slot it landed on
+                        queue.requeue(req.rid)
+                        delayed_pass += 1
+                        continue
+                    if starving is not None and req.rid != starving:
+                        # aging barrier: a request past the deferral bound
+                        # is waiting on pages, and every small admission
+                        # here would snatch them first — steady churn then
+                        # defers the large request forever.  Hold this
+                        # slot empty (re-queue, no deferral penalty) until
+                        # the starving request lands; running slots drain
+                        # and free pages.
+                        queue.push_back(s, req)
+                        continue
+                    with _span("serve.admit", rid=req.rid, slot=s,
+                               prompt_len=req.prompt_len):
+                        try:
+                            if inj is not None:
+                                inj.check_admission(req.rid)
+                            res = backend.admit(s, req, cap_of(req))
+                        except Exception as e:
+                            if not cfg.isolate_failures:
+                                raise
+                            # per-request failure isolation: this admission
+                            # died (a poisoned request, or an organic
+                            # prefill error scoped to it) — the batch
+                            # survives.  The backend reclaims any pages it
+                            # claimed before re-raising, so nothing leaks;
+                            # the request retries or goes FAILED.
+                            if retry_or_fail(
+                                    req,
+                                    f"admission: {type(e).__name__}: {e}"):
+                                delayed_pass += 1
+                            else:
+                                progress = True
+                            continue
+                        if res is None:
+                            # partial admission: the request's page demand
+                            # exceeds the free pool right now — back on
+                            # this slot's backlog (still next in its claim
+                            # order), retry once decode ticks free pages
+                            queue.push_back(s, req)
+                            tm = telem[req.rid]
+                            tm.deferred_ticks += 1
+                            deferred_pass += 1
+                            if (starving is None
+                                    and cfg.max_deferred_ticks is not None
+                                    and tm.deferred_ticks
+                                    > cfg.max_deferred_ticks):
+                                starving = req.rid
+                            continue
+                        progress = True
+                        if req.rid == starving:
+                            starving = None
+                        first = self._sample_row(res.logits_row, seed,
+                                                 req.rid, 0)
+                        slot_req[s] = req
+                        slot_cap[s] = cap_of(req)
+                        slot_len[s] = req.prompt_len
+                        tok[s] = first
+                        outputs[req.rid] = [first]
+                        if spec_k:
+                            # the drafter consumes the same prompt into its
+                            # own contiguous cache row (its proposals must
+                            # continue exactly the target's stream)
+                            w = self._bucket_width(req.prompt_len)
+                            dtoks = np.zeros((1, w), np.int32)
+                            dtoks[0, : req.prompt_len] = req.prompt
+                            _, dcache = self._draft_prefill_padded(
+                                spec.draft_params, jnp.asarray(dtoks),
+                                jnp.asarray([req.prompt_len], jnp.int32))
+                            draft_cache = self._draft_splice(
+                                draft_cache, dcache,
+                                jnp.asarray(s, jnp.int32))
+                    tm = telem[req.rid]
+                    tm.admit_tick = tick
+                    tm.ttft_s = time.monotonic() - t0
+                    tm.stolen = stolen
+                    tm.prefill_tokens = res.prefill_tokens
+                    tm.prefix_hit_tokens = res.prefix_hit_tokens
+                    if first == cfg.eos_id or slot_cap[s] <= 1:
+                        finish(s)
+
+                live = [s for s in range(cfg.slots)
+                        if slot_req[s] is not None]
+                if not live and queue.pending == 0:
+                    break
+                if not live:
+                    if progress:
+                        continue    # every admitted request finished on
+                                    # its first token; loop back for the rest
+                    if delayed_pass:
+                        # everything actionable is waiting out a retry
+                        # backoff and nothing is running: only the clock
+                        # can move, so charge an idle tick and retry
+                        # admission
+                        tick += 1
+                        continue
+                    # true admission deadlock: nothing running, nothing
+                    # admitted, and no decode tick can free pages —
+                    # retrying is a spin.  cfg.on_pressure picks the blast
+                    # radius.
+                    if cfg.on_pressure == "shed":
+                        # load shedding: drop the youngest request already
+                        # bounced on pressure (max rid = latest submission
+                        # — the oldest deferred request keeps its aging
+                        # credit), then let the survivors admit into the
+                        # freed demand
+                        pend = queue.pending_rids()
+                        deferred = [r for r in pend
+                                    if telem[r].deferred_ticks > 0]
+                        victim = max(deferred) if deferred else max(pend)
+                        queue.drop(victim)
+                        set_terminal(victim, "shed",
+                                     "load shed: admission deadlock under "
+                                     "page pressure")
+                        continue
+                    if cfg.on_pressure == "defer":
+                        # graceful completion: requests that can never
+                        # admit go terminal FAILED and the batch ends
+                        # around them
+                        for r in list(queue.pending_rids()):
+                            queue.drop(r)
+                            set_terminal(r, "failed",
+                                         "page pressure: admission can "
+                                         "never proceed")
+                        continue
+                    # "raise" — the pre-robustness behavior, still the
+                    # default
+                    raise RuntimeError(
+                        f"refill deadlock: {queue.pending} request(s) "
+                        f"pending, no slot live, and no admission can "
+                        f"proceed")
+
+                if inj is not None:
+                    # injected decode-loop stall (a straggler engine
+                    # tick): charged to the chaos clock and surfaced in
+                    # the report's injected_stall_s — the exposed-wait term
+                    engine_stall_s += inj.engine_stall(tick)
+                # one unit of per-token decode bookkeeping per (live slot,
+                # tick) — the serving analogue of the per-item FAA the
+                # paper amortizes; speculation emits >1 token per unit
+                decode_slot_ticks += len(live)
+                with _span("serve.decode"):
+                    if spec_k:
+                        # ---- draft: k sequential batched drafter steps.
+                        # Column 0 is each slot's last emitted (still
+                        # unconsumed) token; columns 1..k are the
+                        # drafter's greedy continuations.
+                        draft_block = np.zeros((cfg.slots, spec_k + 1),
+                                               np.int32)
+                        draft_block[:, 0] = tok
+                        dtok = jnp.asarray(tok)[:, None]
+                        for j in range(1, spec_k + 1):
+                            dlogits, draft_cache = self._draft_decode(
+                                spec.draft_params, dtok, draft_cache)
+                            dtok = self._argmax(dlogits)[:, None]
+                            draft_block[:, j] = np.asarray(dtok)[:, 0]
+                        # ---- verify all k+1 positions in one batched
+                        # forward; greedy[s, j] is exactly the token a
+                        # non-speculative decode tick would emit after
+                        # consuming draft_block[s, :j+1] (per-position
+                        # attention in attn_apply)
+                        vlogits, backend.cache = self._verify(
+                            self.params, jnp.asarray(draft_block),
+                            backend.cache)
+                        greedy = np.asarray(self._argmax(vlogits))
+                    else:
+                        logits, backend.cache = self._decode(
+                            self.params, jnp.asarray(tok)[:, None],
+                            backend.cache)
+                        if cfg.temperature <= 0:
+                            next_toks = np.asarray(self._argmax(logits))
+                        else:
+                            # batched per-(request, step) sampling: one
+                            # transfer per tick ([B] ids), never a
+                            # per-slot host sync
+                            rids_b = np.zeros(cfg.slots, np.int32)
+                            steps_b = np.zeros(cfg.slots, np.int32)
+                            for s in live:
+                                rids_b[s] = slot_req[s].rid
+                                steps_b[s] = len(outputs[slot_req[s].rid])
+                            next_toks = np.asarray(self._sample_tokens(
+                                logits, seed, jnp.asarray(rids_b),
+                                jnp.asarray(steps_b)))
+                tick += 1
+                tick_end_s.append(time.monotonic() - t0)
+                with _span("serve.emit"):
+                    if spec_k:
+                        # ---- host acceptance: longest matching prefix +
+                        # one corrected token, capped by remaining budget,
+                        # cut at eos
+                        decisions = {}
+                        full_accept = False
+                        for s in live:
+                            rid = slot_req[s].rid
+                            degraded = False
+                            if inj is not None:
+                                try:
+                                    inj.check_draft(rid, len(outputs[rid]))
+                                except Exception:
+                                    if not cfg.isolate_failures:
+                                        raise
+                                    # poisoned draft: degrade this slot's
+                                    # tick to non-speculative decode
+                                    # (accept nothing, emit only the
+                                    # corrected token) — the request
+                                    # survives, it just loses the
+                                    # amortization
+                                    degraded = True
+                            m = 0
+                            if not degraded:
+                                while (m < spec_k
+                                       and int(draft_block[s, m + 1])
+                                       == int(greedy[s, m])):
+                                    m += 1
+                            if m == spec_k:
+                                full_accept = True
+                            rem = int(slot_cap[s]) - len(outputs[rid])
+                            emit = [int(t) for t in
+                                    greedy[s, : min(m + 1, rem)]]
+                            for ei, t in enumerate(emit):
+                                if t == cfg.eos_id:
+                                    emit = emit[: ei + 1]
+                                    break
+                            decisions[s] = (emit, degraded)
+                        if full_accept:
+                            # resync: a fully accepted row's drafter never
+                            # consumed its own k-th proposal; one extra
+                            # batched step feeds it (the length rollback
+                            # right below masks this step for every other
+                            # row)
+                            _, draft_cache = self._draft_decode(
+                                spec.draft_params,
+                                jnp.asarray(draft_block[:, -1:]),
+                                draft_cache)
+                        for s, (emit, _) in decisions.items():
+                            slot_len[s] += len(emit)
+                        # ---- rollback: both caches truncate to the
+                        # accepted lengths; rejected positions become
+                        # masked garbage (exactly zero attention weight)
+                        # until overwritten
+                        lens = jnp.asarray(slot_len, jnp.int32)
+                        backend.cache = self._set_lens(backend.cache, lens)
+                        draft_cache = self._set_lens(draft_cache, lens)
+                        for s in live:
+                            rid = slot_req[s].rid
+                            emit, degraded = decisions[s]
+                            tm = telem[rid]
+                            tm.drafted_tokens += spec_k
+                            tm.accepted_tokens += len(emit) - 1
+                            drafted_total += spec_k
+                            accepted_total += len(emit) - 1
+                            if degraded:
+                                degraded_ticks += 1
+                            if inj is not None:
+                                cancelled = False
+                                base = len(outputs[rid])
+                                for off in range(len(emit)):
+                                    try:
+                                        inj.check_decode(rid, base + off)
+                                    except Exception as e:
+                                        if not cfg.isolate_failures:
+                                            raise
+                                        cancel(s, f"decode: "
+                                                  f"{type(e).__name__}: {e}")
+                                        cancelled = True
+                                        break
+                                if cancelled:
+                                    continue
+                            outputs[rid].extend(emit)
+                            tok[s] = emit[-1]
+                            if (emit[-1] == cfg.eos_id
+                                    or len(outputs[rid]) >= slot_cap[s]):
+                                finish(s)
+                    else:
+                        for s in live:
+                            rid = slot_req[s].rid
+                            if inj is not None:
+                                try:
+                                    inj.check_decode(rid, len(outputs[rid]))
+                                except Exception as e:
+                                    if not cfg.isolate_failures:
+                                        raise
+                                    cancel(s, f"decode: "
+                                              f"{type(e).__name__}: {e}")
+                                    continue
+                            nxt_tok = int(next_toks[s])
+                            tok[s] = nxt_tok
+                            outputs[rid].append(nxt_tok)
+                            if (nxt_tok == cfg.eos_id
+                                    or len(outputs[rid]) >= slot_cap[s]):
+                                finish(s)
+                    if cfg.deadline_ticks is not None:
+                        for s in range(cfg.slots):
+                            req = slot_req[s]
+                            if req is None:
+                                continue
+                            if (tick - telem[req.rid].admit_tick
+                                    >= cfg.deadline_ticks):
+                                cancel(s, f"deadline: exceeded "
+                                          f"{cfg.deadline_ticks} decode "
+                                          f"tick(s) since admission")
 
         missing = [r.rid for r in requests if r.rid not in terminal]
         if missing:
@@ -906,6 +979,7 @@ class Engine:
         rep.accepted_tokens = accepted_total
         rep.draft_degraded_ticks = degraded_ticks
         rep.decode_slot_ticks = decode_slot_ticks
+        rep.tick_end_s = tick_end_s
         return results
 
     # --------------------------------------------- legacy round barrier

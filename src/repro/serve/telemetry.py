@@ -56,11 +56,6 @@ class RequestTelemetry:
     def latency_s(self) -> float:
         return self.finish_s
 
-    @property
-    def decode_tokens_per_s(self) -> float:
-        d = self.finish_s - self.ttft_s
-        return self.decode_tokens / d if d > 0 else float("nan")
-
 
 @dataclasses.dataclass
 class ServeReport:
@@ -109,6 +104,10 @@ class ServeReport:
     # the per-item FAA.  Speculation emits >1 token per pair; that ratio
     # is the paper's amortization, measured (see faa_per_token).
     decode_slot_ticks: int = 0
+    # engine-clock seconds (like ``ttft_s``) at which each decode tick's
+    # tokens reached the host; the gaps between them are the inter-tick
+    # times.  Continuous mode only: empty under ``rounds``.
+    tick_end_s: List[float] = dataclasses.field(default_factory=list)
 
     @property
     def wasted_tokens(self) -> int:

@@ -134,6 +134,32 @@ def attention(q, k, v, *, causal=True, block_k=None, kv_len=None,
     )
 
 
+def attend_cache_and_new(q, k, v, kv_len, k_new, v_new):
+    """Attention of one query per row over the row's first ``kv_len``
+    cache positions and the query's own new K/V.
+
+    q [B, Hq, D]; k, v [B, Smax, Hkv, D] (positions >= kv_len masked);
+    kv_len [B]; k_new, v_new [B, Hkv, D].  The scores of the two parts are
+    joined before one float32 softmax; no K/V is concatenated, so the
+    cache need not hold the new token yet (the in-place decode write).
+    """
+    b, hq, d = q.shape
+    hkv = k.shape[2]
+    g = hq // hkv
+    qf = q.astype(jnp.float32).reshape(b, hkv, g, d)
+    s_old = jnp.einsum("bhgd,bkhd->bhgk", qf,
+                       k.astype(jnp.float32)) / np.sqrt(d)
+    s_new = jnp.einsum("bhgd,bhd->bhg", qf,
+                       k_new.astype(jnp.float32)) / np.sqrt(d)
+    live = jnp.arange(k.shape[1])[None, :] < kv_len[:, None]
+    s_old = jnp.where(live[:, None, None, :], s_old, NEG_INF)
+    p = jax.nn.softmax(jnp.concatenate([s_old, s_new[..., None]], axis=-1),
+                       axis=-1)
+    o = (jnp.einsum("bhgk,bkhd->bhgd", p[..., :-1], v.astype(jnp.float32))
+         + p[..., -1:] * v_new.astype(jnp.float32)[:, :, None, :])
+    return o.reshape(b, hq, v.shape[-1]).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Standard GQA attention block (projections + rope + cache)
 # ---------------------------------------------------------------------------
@@ -228,8 +254,15 @@ def attn_apply(
     positions: Optional[jax.Array] = None,
     block_k: Optional[int] = None,
     use_kernel: bool = False,
+    append_only: bool = False,
 ):
-    """Returns (out [B,S,d], new_cache or None)."""
+    """Returns (out [B,S,d], new_cache or None).
+
+    ``append_only``: the cache is read and not written, and ``new_cache``
+    holds only the new tokens' leaves as the cache stores them (``"k"``,
+    ``"v"`` [B,S,Hkv,D], plus ``"ks"``/``"vs"`` when quantized) for the
+    caller to write (``Model.decode_step``'s in-place write of a per-row
+    contiguous cache)."""
     b, s, _ = x.shape
     hd, hq, hkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     src = kv if kv is not None else x
@@ -238,8 +271,6 @@ def attn_apply(
     v = layers.dense(p["wv"], src).reshape(b, src.shape[1], hkv, hd)
 
     new_cache = None
-    kv_len = None
-    q_offset = None
     if cache is not None:
         length = cache["len"]
         # Per-row cache lengths ([B] vector instead of scalar) are the
@@ -260,21 +291,28 @@ def attn_apply(
                                   cfg.rope_theta)
             k = layers.apply_rope(k, jnp.broadcast_to(kpos, (b, src.shape[1])),
                                   cfg.rope_theta)
-        # Quantized cache ("ks"/"vs" scale leaves present): tokens are
-        # quantized per (token, head) vector on write, and reads
-        # dequantize before the attention math.  Both paged and
-        # contiguous writes route through the same quantize call, so
-        # paged decode stays bit-identical to the contiguous cache just
-        # like the float path.  (The Pallas paged decode kernel applies
-        # the same scales in-kernel, post-matmul —
-        # kernels/decode_attention.paged_decode_attention_quantized.)
-        quantized = "ks" in cache
+        # The new tokens as the cache stores them: cast to its dtype, or,
+        # for a quantized cache ("ks"/"vs" scale leaves present), quantized
+        # per (token, head) vector.  Every write below stores these same
+        # leaves and reads dequantize before the attention math, so paged,
+        # contiguous and in-place decode read back the same values.  (The
+        # Pallas paged decode kernel applies the same scales in-kernel,
+        # post-matmul — kernels/decode_attention.paged_decode_attention_
+        # quantized.)
+        if "ks" in cache:
+            kq_t, ks_t = quant.quantize(k, dtype=cache["k"].dtype,
+                                        scale_dtype=cache["ks"].dtype)
+            vq_t, vs_t = quant.quantize(v, dtype=cache["v"].dtype,
+                                        scale_dtype=cache["vs"].dtype)
+            tok = {"k": kq_t, "ks": ks_t, "v": vq_t, "vs": vs_t}
+        else:
+            tok = {"k": k.astype(cache["k"].dtype),
+                   "v": v.astype(cache["v"].dtype)}
+        k_tok, v_tok = _read_kv(tok)
 
-        def _quant_tok(t, ref, sref):
-            return quant.quantize(t, dtype=ref.dtype, scale_dtype=sref.dtype)
-
-        paged = "pt" in cache
-        if paged:
+        if append_only:
+            new_cache, view = tok, cache
+        elif "pt" in cache:
             # Paged decode: k/v are a SHARED page pool [Np+1, ps, Hkv, D]
             # (pool index 0 = reserved scratch), "pt" [B, P] maps each
             # row's logical pages to pool pages.  Write one token into the
@@ -297,102 +335,49 @@ def attn_apply(
             page = jnp.minimum(steps // ps, pcount - 1)
             phys = jnp.take_along_axis(pt, page, axis=1)
             off = steps % ps
-            if quantized:
-                kq_t, ks_t = _quant_tok(k, cache["k"], cache["ks"])
-                vq_t, vs_t = _quant_tok(v, cache["v"], cache["vs"])
-                ck = cache["k"].at[phys, off].set(kq_t)
-                cv = cache["v"].at[phys, off].set(vq_t)
-                cks = cache["ks"].at[phys, off].set(ks_t)
-                cvs = cache["vs"].at[phys, off].set(vs_t)
-                new_cache = {"k": ck, "ks": cks, "v": cv, "vs": cvs,
-                             "pt": pt, "len": length + s}
-                k = quant.dequantize(
-                    ck[pt].reshape(b, pcount * ps, hkv, hd),
-                    cks[pt].reshape(b, pcount * ps, hkv, 1))
-                v = quant.dequantize(
-                    cv[pt].reshape(b, pcount * ps, hkv, hd),
-                    cvs[pt].reshape(b, pcount * ps, hkv, 1))
-            else:
-                ck = cache["k"].at[phys, off].set(
-                    k.astype(cache["k"].dtype))
-                cv = cache["v"].at[phys, off].set(
-                    v.astype(cache["v"].dtype))
-                new_cache = {"k": ck, "v": cv, "pt": pt, "len": length + s}
-                k = ck[pt].reshape(b, pcount * ps, hkv, hd)
-                v = cv[pt].reshape(b, pcount * ps, hkv, hd)
+            written = {n: cache[n].at[phys, off].set(t)
+                       for n, t in tok.items()}
+            new_cache = {**written, "pt": pt, "len": length + s}
+            view = {n: c[pt].reshape((b, pcount * ps) + c.shape[2:])
+                    for n, c in written.items()}
         elif per_row:
             # each row writes its token at its own position
             upd = lambda c, u, l: jax.lax.dynamic_update_slice(c, u, (l, 0, 0))
-            if quantized:
-                kq_t, ks_t = _quant_tok(k, cache["k"], cache["ks"])
-                vq_t, vs_t = _quant_tok(v, cache["v"], cache["vs"])
-                ck = jax.vmap(upd)(cache["k"], kq_t, length)
-                cv = jax.vmap(upd)(cache["v"], vq_t, length)
-                cks = jax.vmap(upd)(cache["ks"], ks_t, length)
-                cvs = jax.vmap(upd)(cache["vs"], vs_t, length)
-                new_cache = {"k": ck, "ks": cks, "v": cv, "vs": cvs,
-                             "len": length + s}
-                k = quant.dequantize(ck, cks)
-                v = quant.dequantize(cv, cvs)
-            else:
-                ck = jax.vmap(upd)(cache["k"], k.astype(cache["k"].dtype),
-                                   length)
-                cv = jax.vmap(upd)(cache["v"], v.astype(cache["v"].dtype),
-                                   length)
-                new_cache = {"k": ck, "v": cv, "len": length + s}
-                k, v = ck, cv
+            view = {n: jax.vmap(upd)(cache[n], t, length)
+                    for n, t in tok.items()}
+            new_cache = {**view, "len": length + s}
         else:
-            if quantized:
-                kq_t, ks_t = _quant_tok(k, cache["k"], cache["ks"])
-                vq_t, vs_t = _quant_tok(v, cache["v"], cache["vs"])
-                ck = jax.lax.dynamic_update_slice(
-                    cache["k"], kq_t, (0, length, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cache["v"], vq_t, (0, length, 0, 0))
-                cks = jax.lax.dynamic_update_slice(
-                    cache["ks"], ks_t, (0, length, 0, 0))
-                cvs = jax.lax.dynamic_update_slice(
-                    cache["vs"], vs_t, (0, length, 0, 0))
-                new_cache = {"k": ck, "ks": cks, "v": cv, "vs": cvs,
-                             "len": length + s}
-                k = quant.dequantize(ck, cks)
-                v = quant.dequantize(cv, cvs)
-            else:
-                ck = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, length, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, length, 0, 0))
-                new_cache = {"k": ck, "v": cv, "len": length + s}
-                k, v = ck, cv
+            view = {n: jax.lax.dynamic_update_slice(cache[n], t,
+                                                    (0, length, 0, 0))
+                    for n, t in tok.items()}
+            new_cache = {**view, "len": length + s}
+        k, v = _read_kv(view)
         from repro.distributed.sharding import active_policy
         pol = active_policy()
-        if (s == 1 and pol is not None and pol.decode_seq_shard
+        # the sequence-sharded flash-decode reads the cache after the write
+        if (s == 1 and not append_only and pol is not None
+                and pol.decode_seq_shard
                 and "model" in pol.mesh.shape
                 and k.shape[1] % pol.mesh.shape["model"] == 0):
             out = distributed_decode_attention(
                 q[:, 0], k, v, length + s, mesh=pol.mesh)[:, None]
-        elif per_row:
-            if s == 1:
-                # the causal mask (kpos <= row position) and the valid-
-                # length mask (kpos < length + 1) coincide, so kv_len alone
-                # carries the per-row masking.
-                out = attention(q, k, v, causal=False, block_k=block_k,
-                                kv_len=length + s, q_offset=0,
-                                use_kernel=use_kernel)
-            else:
-                # Speculative verify: position j must see exactly the KV
-                # set a single-token decode at row length length+j would
-                # see, so run one s==1-shaped attention per position with
-                # kv_len = length + j + 1 and concatenate.  s is static,
-                # so this unrolls under jit; each call is arithmetically
-                # identical to the decode-tick call above, which is what
-                # makes speculative greedy output bit-identical to
-                # non-speculative greedy output.
-                out = jnp.concatenate(
-                    [attention(q[:, j:j + 1], k, v, causal=False,
-                               block_k=block_k, kv_len=length + j + 1,
-                               q_offset=0, use_kernel=use_kernel)
-                     for j in range(s)], axis=1)
+        elif per_row or s == 1:
+            # Decode positions.  Query j of row b sits at position
+            # length[b] + j and sees the row's first length[b] + j cache
+            # positions plus its own K/V — what a one-token decode step at
+            # that length sees — so speculative verify (s > 1), paged and
+            # contiguous decode, with or without the write in place, give
+            # the same bits.  A row already at the end of the cache
+            # rewrites its last position (dynamic_update_slice clamps),
+            # so it sees the first Smax - 1.  s is static: the loop
+            # unrolls under jit.
+            lens = jnp.broadcast_to(length, (b,))
+            last = k.shape[1] - 1
+            out = jnp.stack(
+                [attend_cache_and_new(q[:, j], k, v,
+                                      jnp.minimum(lens + j, last),
+                                      k_tok[:, j], v_tok[:, j])
+                 for j in range(s)], axis=1)
         else:
             # causal alignment: query i sits at absolute position length+i,
             # so q_offset is the (dynamic) pre-update cache length.
@@ -409,6 +394,15 @@ def attn_apply(
                         use_kernel=use_kernel)
     out = layers.dense(p["wo"], out.reshape(b, s, hq * hd))
     return out, new_cache
+
+
+def _read_kv(c):
+    """(k, v) of a cache tree or of new-token leaves, dequantized when the
+    tree carries "ks"/"vs" scales."""
+    if "ks" in c:
+        return (quant.dequantize(c["k"], c["ks"]),
+                quant.dequantize(c["v"], c["vs"]))
+    return c["k"], c["v"]
 
 
 def init_kv_cache(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.bfloat16):

@@ -176,15 +176,21 @@ class Model:
 
     # ------------------------------------------------------------- backbone
 
-    def _backbone(self, params, x, batch, caches=None, *, train=False):
-        """x: [B,S,d] embedded tokens. Returns (x, new_caches, aux)."""
+    def _backbone(self, params, x, batch, caches=None, *, train=False,
+                  append_only=False):
+        """x: [B,S,d] embedded tokens. Returns (x, new_caches, aux).
+
+        ``append_only`` (dense and MoE): the layers read ``caches`` and
+        return only the new tokens' leaves, stacked like the cache, in
+        place of new caches (see :meth:`decode_step`)."""
         cfg = self.cfg
         fam = cfg.family
         remat = train
 
         if fam == "dense":
             return tfm.scan_layers(
-                lambda p, xc, c: tfm.dense_block_apply(p, cfg, xc, cache=c),
+                lambda p, xc, c: tfm.dense_block_apply(
+                    p, cfg, xc, cache=c, append_only=append_only),
                 params["blocks"], x, caches, remat=remat, remat_policy=cfg.remat_policy)
 
         if fam == "moe":
@@ -194,14 +200,15 @@ class Model:
             if nd:
                 c0 = caches["dense0"] if caches is not None else None
                 x, nc0, a0 = tfm.scan_layers(
-                    lambda p, xc, c: tfm.dense_block_apply(p, cfg, xc,
-                                                           cache=c),
+                    lambda p, xc, c: tfm.dense_block_apply(
+                        p, cfg, xc, cache=c, append_only=append_only),
                     params["dense0"], x, c0, remat=remat, remat_policy=cfg.remat_policy)
                 new_caches["dense0"] = nc0
                 aux += a0
             cm = caches["blocks"] if caches is not None else None
             x, ncm, am = tfm.scan_layers(
-                lambda p, xc, c: tfm.moe_block_apply(p, cfg, xc, cache=c),
+                lambda p, xc, c: tfm.moe_block_apply(
+                    p, cfg, xc, cache=c, append_only=append_only),
                 params["blocks"], x, cm, remat=remat, remat_policy=cfg.remat_policy)
             new_caches["blocks"] = ncm
             aux += am
@@ -417,14 +424,38 @@ class Model:
         return logits.astype(jnp.float32), cache
 
     def decode_step(self, params, tokens, cache):
-        """tokens: [B,1] -> (logits [B,V], new cache)."""
+        """tokens: [B,1] -> (logits [B,V], new cache).
+
+        A cache that :meth:`writes_in_place` is only read by the layers,
+        which emit each row's new K/V; one scatter per leaf then writes
+        them at (layer, row, len[row]).  Under a jit that donates the
+        cache (``Engine`` does), the step writes those few bytes into the
+        cache's own buffer instead of rewriting the whole cache.  Any
+        other cache takes the layers' own write."""
         cfg = self.cfg
         batch = {"tokens": tokens}
         x = layers.embed(params["embed"], tokens).astype(cfg.dtype)
-        x, cache, _ = self._backbone(params, x, batch, cache, train=False)
+        in_place = tokens.shape[1] == 1 and self.writes_in_place(cache)
+        x, new, _ = self._backbone(params, x, batch, cache, train=False,
+                                   append_only=in_place)
+        cache = _write_tokens(cache, new) if in_place else new
         x = layers.rmsnorm(params["ln_f"], x, cfg.norm_eps)
         logits = self._logits(params, x)[:, 0]
         return logits.astype(jnp.float32), cache
+
+    def writes_in_place(self, cache) -> bool:
+        """Whether :meth:`decode_step` writes ``cache`` in place: the
+        continuous-serve form of a dense or MoE stack of standard
+        attention caches (contiguous k/v leaves, quantized or not, no
+        page table, per-row ``len``).  Paged pools, scalar lengths, MLA
+        latents, recurrent state and cross K/V keep the layers' own
+        write."""
+        cfg = self.cfg
+        if cfg.family not in ("dense", "moe") or cfg.use_mla:
+            return False
+        stacks = [cache] if cfg.family == "dense" else list(cache.values())
+        return all("pt" not in c and len(c["len"].shape) == 2
+                   for c in stacks)
 
     def verify_step(self, params, tokens, cache):
         """tokens: [B,S] -> (logits [B,S,V], new cache).
@@ -821,3 +852,20 @@ class Model:
         x = layers.rmsnorm(params["ln_f"], x[:, -1:], cfg.norm_eps)
         logits = self._logits(params, x)[:, 0]
         return logits.astype(jnp.float32), cache
+
+
+def _write_tokens(cache, tokens):
+    """Write the layers' new-token leaves ``[L, B, 1, ...]`` into a
+    layer-stacked per-row cache at (layer, row, len[row]), one scatter per
+    leaf, and advance ``len`` by one.  A row already at the end of the
+    cache rewrites its last position, as ``dynamic_update_slice`` clamps."""
+    if "len" not in cache:                  # MoE: one stack per block kind
+        return {n: _write_tokens(cache[n], tokens[n]) for n in cache}
+    lens = cache["len"]                                    # [L, B]
+    n_layers, b = lens.shape
+    pos = jnp.minimum(lens, cache["k"].shape[2] - 1)
+    at = (jnp.arange(n_layers)[:, None], jnp.arange(b)[None, :], pos)
+    out = {n: cache[n].at[at].set(t[:, :, 0], unique_indices=True)
+           for n, t in tokens.items()}
+    out["len"] = lens + 1
+    return out

@@ -82,7 +82,9 @@ def dense_block_init(key, cfg: ModelConfig, *, d_ff=None, dtype=jnp.float32):
     }
 
 
-def dense_block_apply(p, cfg: ModelConfig, x, *, cache=None, block_k=None):
+def dense_block_apply(p, cfg: ModelConfig, x, *, cache=None, block_k=None,
+                      append_only=False):
+    """``append_only``: see ``attention.attn_apply`` (non-MLA only)."""
     block_k = block_k or (cfg.attn_block_k or None)
     h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
     if cfg.use_mla:
@@ -90,7 +92,8 @@ def dense_block_apply(p, cfg: ModelConfig, x, *, cache=None, block_k=None):
                                      block_k=block_k)
     else:
         a, new_cache = attn_mod.attn_apply(p["attn"], attn_cfg(cfg), h,
-                                           cache=cache, block_k=block_k)
+                                           cache=cache, block_k=block_k,
+                                           append_only=append_only)
     x = x + a
     h = layers.rmsnorm(p["ln2"], x, cfg.norm_eps)
     x = x + layers.mlp(p["mlp"], h, act=cfg.act)
@@ -109,7 +112,8 @@ def moe_block_init(key, cfg: ModelConfig, dtype=jnp.float32):
     }
 
 
-def moe_block_apply(p, cfg: ModelConfig, x, *, cache=None, block_k=None):
+def moe_block_apply(p, cfg: ModelConfig, x, *, cache=None, block_k=None,
+                    append_only=False):
     block_k = block_k or (cfg.attn_block_k or None)
     h = layers.rmsnorm(p["ln1"], x, cfg.norm_eps)
     if cfg.use_mla:
@@ -117,7 +121,8 @@ def moe_block_apply(p, cfg: ModelConfig, x, *, cache=None, block_k=None):
                                      block_k=block_k)
     else:
         a, new_cache = attn_mod.attn_apply(p["attn"], attn_cfg(cfg), h,
-                                           cache=cache, block_k=block_k)
+                                           cache=cache, block_k=block_k,
+                                           append_only=append_only)
     x = x + a
     h = layers.rmsnorm(p["ln2"], x, cfg.norm_eps)
     if cfg.moe_impl == "sharded":

@@ -183,7 +183,9 @@ class Engine:
 
         self._prefill = jax.jit(prefill)
         self._prefill_padded = jax.jit(prefill_padded)
-        self._decode = jax.jit(model.decode_step)
+        # a cache the model writes in place is donated (see _decode)
+        self._decode_in_place = jax.jit(model.decode_step, donate_argnums=2)
+        self._decode_kept = jax.jit(model.decode_step)
         self._argmax = jax.jit(argmax_tokens)
         # temperature > 0: one batched categorical per tick over the
         # per-(request, step) key streams — same [B]-ids-only transfer
@@ -207,8 +209,12 @@ class Engine:
         if cfg.spec is not None:
             draft = cfg.spec.draft
 
+            # the drafter's cache is not donated, so its one-token step
+            # keeps the layers' own write: verify_step at one token, the
+            # arithmetic of decode_step
             def drafter_step(p, tokens, cache):
-                return draft.decode_step(p, tokens, cache)
+                logits, cache = draft.verify_step(p, tokens, cache)
+                return logits[:, 0], cache
 
             def drafter_prefill_padded(p, toks, lens):
                 return draft.prefill_padded(
@@ -229,6 +235,16 @@ class Engine:
         # ScheduleStats of each slot-refill / admission pass (see serve())
         self.refill_stats: list = []
         self.last_report: Optional[ServeReport] = None
+
+    def _decode(self, params, tokens, cache):
+        """One decode step, ``jit_decode_step``.  A cache the model writes
+        in place (``Model.writes_in_place``: the contiguous serve cache,
+        a padded prefill's) is donated, so the step writes only the new
+        tokens into it and the caller must rebind the returned cache; any
+        other (paged pool, scalar lengths) is kept."""
+        step = (self._decode_in_place if self.model.writes_in_place(cache)
+                else self._decode_kept)
+        return step(params, tokens, cache)
 
     def reset_cache(self) -> None:
         """Drop the persistent serve cache backend (page pool, prefix

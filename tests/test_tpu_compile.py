@@ -12,6 +12,7 @@ given this file loads the TPU library.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ from repro.kernels.decode_attention.ops import (decode_attention,
                                                 paged_decode_attention)
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.models import Model
+from repro.serve.engine import Engine, ServeConfig
 
 ARCH = "qwen2.5-3b"
 SLOTS, PROMPT, MAX_LEN = 4, 512, 2048
@@ -106,6 +108,30 @@ def test_decode_step_fits_one_chip(model, one_chip):
         _params(model, one_chip), tokens, _on(one_chip, cache)).compile()
     args, out, temp = _memory(compiled)
     assert args + out + temp < HBM_BYTES
+
+
+def test_served_decode_step_writes_its_cache_in_place(model, one_chip):
+    """The engine's decode program at the benchmark cell's shapes (32
+    slots, max_len 2048, bf16): the cache is donated and written in
+    place — its buffer aliased to the output, no scratch the size of one
+    layer's K, and no loop but the layer scan (no per-row write loops)."""
+    slots = 32
+    eng = Engine(model, None, ServeConfig(max_len=MAX_LEN, slots=slots,
+                                          cache_dtype="bfloat16"))
+    cache = jax.eval_shape(lambda: model.set_cache_lengths(
+        model.init_cache(slots, MAX_LEN, jnp.bfloat16),
+        jnp.zeros(slots, jnp.int32)))
+    assert model.writes_in_place(cache)
+    tokens = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    compiled = eng._decode_in_place.lower(
+        _params(model, one_chip), tokens, _on(one_chip, cache)).compile()
+    ma = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    layer_k = slots * MAX_LEN * HKV * HD * 2
+    assert ma.alias_size_in_bytes >= cache_bytes
+    assert ma.temp_size_in_bytes < layer_k
+    assert len(re.findall(r"\swhile\(", compiled.as_text())) == 1
 
 
 def _assert_kernel(fn, *shapes):

@@ -2,7 +2,9 @@
 the reference, and the metrics that ``BENCHMARK.json`` names.
 
 A cell is data.  ``BENCHMARK.json`` names its configuration
-(``bench/configs/<config>.json``) and traffic mix
+(``bench/configs/<config>.json``), whose ``model_type`` names the family
+module that builds, checks and counts the model
+(``bench/families/<model_type>.py``), and its traffic mix
 (``bench/traffic/<mix>.json``); its serve settings and the limits of its
 check are in ``bench/cells/<workload>.json``; each metric is read by
 ``bench/metrics/<metric>.py``, a module with ``read(ctx)`` that returns
@@ -10,9 +12,9 @@ a number or None (nothing to read in this cell).
 
 Offline batch serving: the generated stream is cut into calls of
 ``requests_per_call`` requests, and ``Engine.serve`` takes them back to
-back.  No call starts once ``seconds`` have passed; the window runs from
-the first call's start to the last call's end.  A request is submitted
-at its call's start.
+back.  No call starts once ``seconds`` have passed and call number
+``trace_call`` has run; the window runs from the first call's start to
+the last call's end.  A request is submitted at its call's start.
 """
 
 from __future__ import annotations
@@ -31,7 +33,6 @@ from typing import List, Optional
 import numpy as np
 
 from bench import traffic
-from bench.counts import Shapes
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -57,6 +58,35 @@ class CompileClock:
         if event in self.EVENTS:
             self.total += duration
             self.compiles += event == self.EVENTS[-1]
+
+
+class GcClock:
+    """Seconds the interpreter's garbage collector runs, and how many of
+    its collections take the oldest generation, while it is open."""
+
+    def __init__(self):
+        self.total, self.full, self._t = 0.0, 0, None
+        gc.callbacks.append(self._on)
+
+    def _on(self, phase, info):
+        if phase == "start":
+            self._t = time.perf_counter()
+        elif self._t is not None:
+            self.total += time.perf_counter() - self._t
+            self.full += info["generation"] == 2
+
+    def close(self):
+        gc.callbacks.remove(self._on)
+
+
+def tick_stalls(tick_end_s: List[float]) -> str:
+    """The median gap between ticks, and the gaps over three times it:
+    whether a slow call is slow in every tick or in a few."""
+    gaps = np.diff(tick_end_s) if len(tick_end_s) > 1 else np.zeros(1)
+    med = float(np.median(gaps))
+    long = gaps[gaps > 3 * med]
+    return (f"ticks median {1e3 * med:.2f} ms, {len(long)} over 3x "
+            f"({long.sum():.3f} s)")
 
 
 @dataclasses.dataclass
@@ -161,21 +191,25 @@ def warmup_calls(engine, stream) -> List[list]:
 def run_window(engine, stream, cell: dict, seconds: float,
                trace_dir: Optional[str] = None):
     """Back-to-back ``serve()`` calls over consecutive blocks of the
-    stream.  With ``trace_dir`` the profiler records the first
-    ``trace_seconds`` of call number ``trace_call`` (counted from 0; at
-    least that many calls run), between the host spans
-    ``bench.trace_start`` and ``bench.trace_end``."""
+    stream, until ``seconds`` have passed and, traced or not, call number
+    ``trace_call`` (counted from 0) has run: a slow host never leaves a
+    run fewer calls to check.  With ``trace_dir`` the profiler
+    records the first ``trace_seconds`` of that call, between the host
+    spans ``bench.trace_start`` and ``bench.trace_end``."""
     from repro.serve.queue import Request
 
     max_new = max(stream.outs)
-    traced = cell.get("trace_call", 1) if trace_dir is not None else -1
+    last = cell.get("trace_call", 1)
+    traced = last if trace_dir is not None else -1
     calls: List[Call] = []
+    gc_clock = GcClock()
     t_begin = time.monotonic()
-    while time.monotonic() - t_begin < seconds or len(calls) <= traced:
+    while time.monotonic() - t_begin < seconds or len(calls) <= last:
         chunk = stream.block(len(calls))
         reqs = [Request(prompt=r.prompt, max_new_tokens=r.max_new_tokens)
                 for r in chunk]
         tracer = None
+        gc0 = (gc_clock.total, gc_clock.full)
         if len(calls) == traced:
             tracer = Tracer(trace_dir, cell.get("trace_seconds", 5.0))
         t0 = time.monotonic()
@@ -187,8 +221,12 @@ def run_window(engine, stream, cell: dict, seconds: float,
                           tracer.t_stop if tracer else None))
         log(f"call {len(calls)}: {len(reqs)} requests, "
             f"{engine.last_report.total_tokens} tokens, "
-            f"{engine.last_report.total_ticks} ticks, {t1 - t0:.3f} s"
+            f"{engine.last_report.total_ticks} ticks, {t1 - t0:.3f} s; "
+            f"{tick_stalls(engine.last_report.tick_end_s)}; gc "
+            f"{gc_clock.total - gc0[0]:.3f} s, "
+            f"{gc_clock.full - gc0[1]} full"
             + (" (traced)" if tracer else ""))
+    gc_clock.close()
     return calls
 
 
@@ -288,11 +326,12 @@ def run_cell(*, cfg: dict, mix: dict, cell: dict,
     (the tests break the timed path through it)."""
     import jax
 
-    from bench import modeldef, reference
+    from bench import modeldef, spans
     from bench import trace as tr
     from repro.models import Model
     from repro.serve.engine import Engine
 
+    fam = modeldef.family(cfg)
     log(f"program imported at {time.monotonic() - t_process:.3f} s")
     dev = jax.devices()[0]
     if require_tpu and dev.platform != "tpu":
@@ -301,9 +340,9 @@ def run_cell(*, cfg: dict, mix: dict, cell: dict,
         from bench.peaks import peak
         peaks = peak(dev.device_kind)
     clock = CompileClock()
-    mcfg = modeldef.model_config(cfg)
+    mcfg = fam.model_config(cfg)
     model = (model_cls or Model)(mcfg)
-    modeldef.check_layout(cfg, model)
+    modeldef.check_layout(fam.init_fn(cfg), model)
     params = modeldef.make_params(cfg, seed)
     log(f"weights made at {time.monotonic() - t_process:.3f} s; "
         f"{clock.compiles} programs compiled in {clock.total:.3f} s")
@@ -326,15 +365,20 @@ def run_cell(*, cfg: dict, mix: dict, cell: dict,
         red = None
         if trace:
             ev = tr.load(tr.find_xplane(tmp))
-            red = tr.reduce(ev, (tr.span_window(ev, TRACE_START)[0],
-                                 tr.span_window(ev, TRACE_END)[0]))
+            host = spans.serve_spans(ev)
+            window = (tr.span_window(ev, TRACE_START)[0],
+                      tr.span_window(ev, TRACE_END)[0])
+            red = tr.reduce(ev, window)
+            red["idle_by_span"] = spans.idle_by_span(ev, host, window)
+            red["idle_gaps"] = spans.labelled_gaps(ev, host, window)
+            log(f"idle by span: {json.dumps(red['idle_by_span'])}")
     window_s = calls[-1].end - calls[0].start
     mem = dev.memory_stats() or {}
     peak_bytes = int(mem.get("peak_bytes_in_use", 0))
     del engine
     gc.collect()
     t_check = time.monotonic()
-    checks = check(reference.Reference(cfg), params, calls, cell, seed,
+    checks = check(fam.Reference(cfg), params, calls, cell, seed,
                    mcfg.vocab_size, tuple(controls))
     log(f"window {window_s:.3f} s, {window_compiles} compiles; check "
         f"{time.monotonic() - t_check:.3f} s; peak {peak_bytes} bytes")
@@ -342,7 +386,7 @@ def run_cell(*, cfg: dict, mix: dict, cell: dict,
         calls=calls, trace=red,
         traced=calls[cell.get("trace_call", 1)] if trace else None,
         window_s=window_s, setup_s=setup_s, window_compiles=window_compiles,
-        shapes=Shapes.of(cfg), peak=peaks, cell=cell, chips=1)
+        shapes=fam.Shapes.of(cfg), peak=peaks, cell=cell, chips=1)
     out_metrics = {}
     for m in metrics:
         v = load_metric(m["name"]).read(ctx)
@@ -363,8 +407,7 @@ def run_cell(*, cfg: dict, mix: dict, cell: dict,
         device["window_s"] = red["window_s"]
         result["breakdown"] = {
             "device_ops": red["top_programs"],
-            "idle_gaps": [[f"serve call: {n}", s]
-                          for n, s in red["idle_gaps"]],
+            "idle_gaps": red["idle_gaps"],
         }
     result["checks"] = checks
     return result

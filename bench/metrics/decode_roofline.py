@@ -2,9 +2,11 @@
 
 The least time of a step is the larger of its operations over peak and
 its bytes over bandwidth, for the work it needs: weights read once, the
-KV of the live rows' positions only, and the new KV written
-(``bench/counts.py``).  Summed over the ``k`` whole ``jit(decode_step)``
-runs in the span (the call's steps ``1..k``), over their device time."""
+KV of the live rows' positions only, and the new KV written, as the
+family's ``Shapes`` counts it (``ctx.shapes``, from
+``bench/families/<model_type>.py``).  Summed over the ``k`` whole
+``jit(decode_step)`` runs in the span (the call's steps ``1..k``), over
+their device time."""
 
 from bench.counts import least_time
 from bench.trace import program_time

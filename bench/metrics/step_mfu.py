@@ -1,7 +1,8 @@
 """Model operations in the traced span over (span x peak x chips): the
 real prompt tokens prefilled by admissions that finished in the span and
 the tokens of the span's whole decode steps, with no padding, masked
-position or idle row counted (``bench/counts.py``)."""
+position or idle row counted, as the family's ``Shapes`` counts it
+(``ctx.shapes``, from ``bench/families/<model_type>.py``)."""
 
 from bench.trace import program_time
 from bench.work import decode_contexts, prefills

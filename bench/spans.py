@@ -36,22 +36,12 @@ NONE = "none"
 TICK = ("serve.tick", "serve.decode", "serve.emit")
 
 
-def load_spans(path: str) -> List[tuple]:
-    """Host events named ``serve.*`` of one ``.xplane.pb``, in order of
-    start: ``(name, start, end, stats)``, where ``stats`` holds the
-    span's keyword arguments (``rid``, ``tick`` ...)."""
-    from jax.profiler import ProfileData
-
-    out = []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for ln in plane.lines:
-            for e in ln.events:
-                if e.name.startswith(PREFIX):
-                    out.append((e.name, e.start_ns, e.end_ns,
-                                dict(e.stats)))
-    return sorted(out, key=lambda s: (s[1], -s[2]))
+def serve_spans(ev: tr.Events) -> List[tuple]:
+    """The trace's ``serve.*`` host spans in order of start:
+    ``(name, start, end, stats)``, where ``stats`` holds the span's
+    keyword arguments (``rid``, ``tick`` ...)."""
+    return sorted((s for s in ev.spans if s[0].startswith(PREFIX)),
+                  key=lambda s: (s[1], -s[2]))
 
 
 def innermost(spans: List[tuple]) -> List[tuple]:
@@ -141,7 +131,7 @@ def labelled_gaps(ev: tr.Events, spans: List[tuple], window: tr.Interval,
                   top: int = 10) -> List[list]:
     """The ``top`` longest idle gaps as ``[label, seconds]``: the span
     that covers most of the gap (``serve call`` where none does), then
-    the programs on either side, as ``reduce`` names them."""
+    the programs on either side."""
     lo, hi = window
     pieces = innermost(spans)
     starts = [p[0] for p in pieces]
@@ -180,7 +170,7 @@ def window_of(ev: tr.Events, spans: List[tuple]) -> Tuple[float, float]:
 def summarize(path: str, top: int = 10) -> dict:
     """Everything :func:`main` prints, for one ``.xplane.pb``."""
     ev = tr.load(path)
-    spans = load_spans(path)
+    spans = serve_spans(ev)
     window = window_of(ev, spans)
     red = tr.reduce(ev, window, top)
     idle = idle_by_span(ev, spans, window)

@@ -1,14 +1,16 @@
-"""Operation and byte counts on hand-worked shapes."""
+"""Operation and byte counts of the dense family on hand-worked shapes."""
 
 import json
 from pathlib import Path
 
 import pytest
 
-from bench.counts import Shapes, least_time
+from bench import modeldef
+from bench.counts import least_time
 from bench.peaks import PEAKS, peak
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
+Shapes = modeldef.family({"model_type": "qwen2"}).Shapes
 
 
 def hand():

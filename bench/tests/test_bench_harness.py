@@ -2,6 +2,7 @@
 window compiles nothing, the check passes on the program, and it fails
 on the control and on each fault planted in the timed path."""
 
+import re
 import time
 
 import jax.numpy as jnp
@@ -16,9 +17,9 @@ E2E = [{"name": n, "unit": u} for n, u in [
 
 
 def run(cell=CONTIGUOUS, mix=CHAT, **kw):
+    kw = {"metrics": E2E, "seed": 2**31 + 3, "trace": False, **kw}
     return harness.run_cell(
-        cfg=config(), mix=mix, cell=cell, metrics=E2E,
-        seed=kw.pop("seed", 2**31 + 3), seconds=0.2, trace=False,
+        cfg=config(), mix=mix, cell=cell, seconds=0.2,
         t_process=time.monotonic(), require_tpu=False, **kw)
 
 
@@ -85,3 +86,17 @@ def _faults():
 def test_fault_in_the_timed_path_is_not_correct(fault, cell, mix):
     res = run(cell, mix, model_cls=_faults()[fault])
     assert not res["correct"], res["checks"]
+
+
+def test_traced_run_splits_idle_by_span_and_labels_its_gaps():
+    per_layer = [{"name": n, "unit": "%"} for n in (
+        "device_idle_share", "idle_admit_share", "idle_tick_share")]
+    res = run(metrics=per_layer, trace=True)
+    assert res["correct"], res["checks"]
+    got = {k: v["value"] for k, v in res["metrics"].items()}
+    assert set(got) == {m["name"] for m in per_layer}
+    assert 0 < got["idle_admit_share"] + got["idle_tick_share"] <= \
+        got["device_idle_share"] + 1e-9
+    gaps = res["breakdown"]["idle_gaps"]
+    assert gaps and all(re.match(r"(serve\.\w+|serve call): ", n)
+                        for n, _ in gaps)

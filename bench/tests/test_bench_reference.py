@@ -1,5 +1,6 @@
-"""The float32 reference against the program's own prefill-then-decode
-logits, on both cache backends, at a tiny size in float32."""
+"""The dense family's float32 reference against the program's own
+prefill-then-decode logits, on both cache backends, at a tiny size in
+float32."""
 
 import jax
 import jax.numpy as jnp
@@ -7,8 +8,10 @@ import numpy as np
 import pytest
 
 from bench import modeldef
-from bench.reference import Reference
 from bench.tests.tiny import config
+
+DENSE = modeldef.family(config())
+Reference = DENSE.Reference
 
 
 def engine_logits(cfg, params, cache, prompts, steps):
@@ -20,7 +23,7 @@ def engine_logits(cfg, params, cache, prompts, steps):
     from repro.serve.paged_cache import make_cache_backend
     from repro.serve.queue import Request
 
-    model = Model(modeldef.model_config(cfg))
+    model = Model(DENSE.model_config(cfg))
     eng = Engine(model, params, ServeConfig(
         max_len=128, slots=len(prompts), cache=cache, page_size=16,
         cache_dtype="float32"))
@@ -80,11 +83,12 @@ def test_layout_is_the_programs():
     from repro.models import Model
 
     cfg = config()
-    modeldef.check_layout(cfg, Model(modeldef.model_config(cfg)))
+    model = Model(DENSE.model_config(cfg))
+    modeldef.check_layout(DENSE.init_fn(cfg), model)
     bad = config()
     bad["tie_word_embeddings"] = False
     with pytest.raises(ValueError):
-        modeldef.check_layout(bad, Model(modeldef.model_config(cfg)))
+        modeldef.check_layout(DENSE.init_fn(bad), model)
 
 
 def test_weights_repeat_per_seed_and_differ_across_seeds():

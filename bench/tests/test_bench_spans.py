@@ -89,7 +89,8 @@ def tiny_model():
     from repro.models import Model
 
     cfg = config()
-    return Model(modeldef.model_config(cfg)), modeldef.make_params(cfg, 7)
+    return (Model(modeldef.family(cfg).model_config(cfg)),
+            modeldef.make_params(cfg, 7))
 
 
 def traced_serve(tiny_model, cell, log_dir, n=8, mode="continuous"):
@@ -117,7 +118,8 @@ def traced_serve(tiny_model, cell, log_dir, n=8, mode="continuous"):
 def test_recorded_serve_has_its_span_tree_and_named_programs(
         cell, tiny_model, tmp_path):
     report, path = traced_serve(tiny_model, cell, tmp_path)
-    spans = sp.load_spans(path)
+    ev = tr.load(path)
+    spans = sp.serve_spans(ev)
     names = [s[0] for s in spans]
     assert names.count("serve.call") == 1 and names.count("serve.plan") == 1
     call = next(s for s in spans if s[0] == "serve.call")
@@ -137,7 +139,6 @@ def test_recorded_serve_has_its_span_tree_and_named_programs(
     assert len(ends) == report.total_ticks
     assert all(0 < a < b < report.wall_s for a, b in zip(ends, ends[1:]))
 
-    ev = tr.load(path)
     programs = {n for runs in ev.programs.values() for n, _, _ in runs}
     assert "jit__lambda" not in programs
     assert {"jit_decode_step", "jit_prefill_padded",
@@ -161,7 +162,7 @@ def test_rounds_mode_has_one_call_span_and_no_tick_ends(tiny_model,
                                                         tmp_path):
     report, path = traced_serve(tiny_model, CONTIGUOUS, tmp_path,
                                 mode="rounds")
-    assert [s[0] for s in sp.load_spans(path)] == ["serve.call"]
+    assert [s[0] for s in sp.serve_spans(tr.load(path))] == ["serve.call"]
     assert report.tick_end_s == []
 
 
@@ -218,3 +219,17 @@ def test_tick_gap_p99_ms_pools_the_gaps_of_every_call():
     # a report without the counter (the parent's) reads nothing
     assert read(NS(calls=[NS(report=NS())])) is None
     assert read(NS(calls=[NS(report=NS(tick_end_s=[0.1]))])) is None
+
+
+@pytest.mark.parametrize("name,want", [("idle_admit_share", 8.0),
+                                       ("idle_tick_share", 52.0)])
+def test_idle_share_readers_read_the_split_of_the_trace(name, want):
+    read = harness.load_metric(name).read
+    ev, spans = nested()
+    red = tr.reduce(ev, (0, 100))
+    red["idle_by_span"] = sp.idle_by_span(ev, spans, (0, 100))
+    assert read(NS(trace=red)) == pytest.approx(want)
+    # a trace with no serve.* span, or none at all: nothing to read
+    red["idle_by_span"] = sp.idle_by_span(ev, [], (0, 100))
+    assert read(NS(trace=red)) is None
+    assert read(NS(trace=None)) is None

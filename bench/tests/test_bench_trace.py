@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from bench import spans as sp
 from bench import trace as tr
 
 
@@ -21,9 +22,12 @@ def test_reduce_hand_made_events():
     # a run cut by the window's end does not count
     assert red["program_runs"] == {"jit_decode_step": 1, "jit_prefill": 1}
     assert tr.program_time(red, "decode_step") == (pytest.approx(15e-9), 1)
-    gaps = {n: s for n, s in red["idle_gaps"]}
-    assert gaps["jit_decode_step -> jit_prefill"] == pytest.approx(5e-9)
-    assert gaps["jit_prefill -> jit_decode_step"] == pytest.approx(5e-9)
+    # idle gaps are named by the programs on either side of them
+    gaps = dict(sp.labelled_gaps(ev, [], (0, 40)))
+    assert gaps["serve call: jit_decode_step -> jit_prefill"] == \
+        pytest.approx(5e-9)
+    assert gaps["serve call: jit_prefill -> jit_decode_step"] == \
+        pytest.approx(5e-9)
     assert red["top_programs"][0][0] == "jit_decode_step"
 
 

@@ -3,7 +3,8 @@
 import copy
 
 TINY = {
-    "name": "tiny", "hidden_act": "silu", "hidden_size": 64,
+    "name": "tiny", "model_type": "qwen2", "hidden_act": "silu",
+    "hidden_size": 64,
     "num_attention_heads": 4, "num_key_value_heads": 2,
     "intermediate_size": 128, "num_hidden_layers": 2, "vocab_size": 2048,
     "rms_norm_eps": 1e-6, "rope_theta": 10000.0,

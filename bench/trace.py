@@ -5,7 +5,8 @@ Loaded with ``jax.profiler.ProfileData``.  On a TPU each chip is a plane
 program run and ``XLA Ops`` one per operation.  On the CPU backend (the
 tests) operations are host events that carry an ``hlo_module`` stat;
 a program run is then the span of its operations with one ``run_id``.
-The harness's own spans are host events named ``bench.*``.
+The host spans kept are the harness's own (``bench.*``) and the serve
+loop's (``serve.*``, read by ``bench/spans.py``).
 
 All times are nanoseconds on the trace's clock until :func:`reduce`
 turns them into seconds.
@@ -13,7 +14,6 @@ turns them into seconds.
 
 from __future__ import annotations
 
-import bisect
 import dataclasses
 import glob
 import re
@@ -21,13 +21,14 @@ from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
 Interval = Tuple[float, float]
+SPANS = ("bench.", "serve.")
 
 
 @dataclasses.dataclass
 class Events:
     programs: Dict[str, List[tuple]]         # device -> [(name, start, end)]
     ops: Dict[str, List[tuple]]              # device -> [(name, start, end)]
-    spans: List[tuple]                       # [(name, start, end)] host
+    spans: List[tuple]              # host [(name, start, end, stats)]
 
 
 def program_name(raw: str) -> str:
@@ -58,8 +59,9 @@ def load(path: str) -> Events:
             continue
         for ln in plane.lines:
             for e in ln.events:
-                if e.name.startswith("bench."):
-                    spans.append((e.name, e.start_ns, e.end_ns))
+                if e.name.startswith(SPANS):
+                    spans.append((e.name, e.start_ns, e.end_ns,
+                                  dict(e.stats)))
                     continue
                 stats = dict(e.stats)
                 mod = stats.get("hlo_module")
@@ -103,35 +105,20 @@ def reduce(ev: Events, window: Interval, top: int = 10) -> dict:
     """Device numbers inside ``window`` (ns).  Busy is the union of the
     operations' intervals, per device, averaged over devices for
     ``busy_s``.  A program is timed by its runs that lie wholly inside the
-    window.  Idle gaps are named by the programs on either side of them."""
+    window."""
     lo, hi = window
     if not ev.ops:
         raise ValueError("the trace holds no device operation")
-    busy, gaps = [], []
+    busy = []
     prog_s, prog_n = defaultdict(float), defaultdict(int)
     for dev, dev_ops in ev.ops.items():
         merged = union([(s, t) for _, s, t in clip(dev_ops, lo, hi)])
         busy.append(sum(t - s for s, t in merged) / 1e9)
-        runs = sorted(clip(ev.programs.get(dev, []), lo, hi),
-                      key=lambda r: r[1])
         # per program, only the runs wholly inside the window
         for name, s, t in ev.programs.get(dev, []):
             if lo <= s and t <= hi:
                 prog_s[name] += (t - s) / 1e9
                 prog_n[name] += 1
-        # runs of one device do not overlap: sorted by start, they are
-        # sorted by end too
-        starts = [r[1] for r in runs]
-        ends = [r[2] for r in runs]
-        edges = [lo] + [x for iv in merged for x in iv] + [hi]
-        for a, b in zip(edges[0::2], edges[1::2]):
-            if b <= a:
-                continue
-            i = bisect.bisect_right(ends, a) - 1
-            j = bisect.bisect_left(starts, b)
-            label = (f"{runs[i][0] if i >= 0 else 'start'} -> "
-                     f"{runs[j][0] if j < len(runs) else 'end'}")
-            gaps.append((label, (b - a) / 1e9))
     window_s = (hi - lo) / 1e9
     busy_s = sum(busy) / len(busy)
     return {
@@ -142,14 +129,12 @@ def reduce(ev: Events, window: Interval, top: int = 10) -> dict:
         "program_runs": dict(prog_n),
         "top_programs": [[n, s] for n, s in sorted(
             prog_s.items(), key=lambda kv: -kv[1])[:top]],
-        "idle_gaps": [[n, s] for n, s in
-                      sorted(gaps, key=lambda g: -g[1])[:top]],
     }
 
 
 def span_window(ev: Events, name: str) -> Optional[Interval]:
     """The first host span called ``name``, or None."""
-    for n, s, t in ev.spans:
+    for n, s, t, *_ in ev.spans:
         if n == name:
             return (s, t)
     return None
